@@ -16,6 +16,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class KVPool(NamedTuple):
@@ -90,26 +91,46 @@ def local_write_batch(pool: KVPool, k_pages, v_pages, slots) -> KVPool:
     )
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _stream_page_jit(pk, pv, k, v, slot):
-    return pk.at[slot].set(k), pv.at[slot].set(v)
+@jax.jit
+def _read_pages_jit(pools, slots):
+    k = jnp.stack([p.k[slots] for p in pools], axis=1)
+    v = jnp.stack([p.v[slots] for p in pools], axis=1)
+    return tuple((k[i], v[i]) for i in range(slots.shape[0]))
 
 
-def stream_page(pool: KVPool, k, v, slot) -> KVPool:
+def read_pages(pools, slots):
+    """The pages in ``slots`` (non-empty) across every paged layer, read on
+    the device in one dispatch: a list of ``(k, v)`` pairs, each
+    ``(n_layers, page, n_kv, hd)``.  This is the spill-side slice: only
+    whole per-page arrays go to the host tier.  The slot list is padded to
+    a power of two, so a handful of compiled programs serve every batch
+    size."""
+    n = len(slots)
+    idx = np.full(1 << (n - 1).bit_length(), slots[-1], np.int32)
+    idx[:n] = slots
+    return list(_read_pages_jit(tuple(pools), jnp.asarray(idx))[:n])
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _stream_page_jit(pools, k, v, slot):
+    return tuple(KVPool(p.k.at[slot].set(k[j]), p.v.at[slot].set(v[j]))
+                 for j, p in enumerate(pools))
+
+
+def stream_page(pools, k, v, slot):
     """On-demand single-page stream-in (the zero-restore miss path).
 
-    k/v: one page ``(page, n_kv, hd)``; ``slot`` a scalar index.  Restore in
-    the zero-restore engine is block-table repointing for every page whose
+    ``k``/``v``: one page of every paged layer, ``(n_layers, page, n_kv,
+    hd)``, as ``read_pages`` produced it, back in device memory
+    (``from_host_tier``); ``slot`` a scalar index.  Restore in the
+    zero-restore engine is block-table repointing for every page whose
     slot survived preemption untouched; only pages whose slot was *reused*
-    come back through here, one host read each, instead of the legacy bulk
+    come back through here, one scatter each, instead of the legacy bulk
     per-layer ``local_write_batch`` scatter over the whole sequence.  The
     pool buffers are donated (in-place scatter, no pool-sized copy) and the
-    slot is a traced argument, so every streamed page of a layer shares one
-    compiled program."""
-    return KVPool(*_stream_page_jit(
-        pool.k, pool.v,
-        jnp.asarray(k, pool.k.dtype), jnp.asarray(v, pool.v.dtype),
-        jnp.asarray(slot, jnp.int32)))
+    slot is a traced argument, so every streamed page shares one compiled
+    program."""
+    return _stream_page_jit(tuple(pools), k, v, jnp.asarray(slot, jnp.int32))
 
 
 def copy_block(pool: KVPool, src_slot: jax.Array, dst_slot: jax.Array) -> KVPool:
@@ -134,30 +155,20 @@ def insert_blocks(pool: KVPool, ks, vs, slots) -> KVPool:
 # -- host tier ----------------------------------------------------------------
 
 def to_host_tier(x):
-    """Spill an array to the host memory tier.
-
-    On TPU this uses the jax memories API (``memory_kind="pinned_host"``) —
-    an async DMA that leaves the data device-addressable; on backends
-    without host memory kinds it falls back to a host numpy copy.  Either
-    way the Valet contract holds: the spill is off the critical path and
-    round-trips exactly.
-    """
-    import numpy as np
-    try:
-        s = x.sharding.with_memory_kind("pinned_host")
-        return jax.device_put(x, s)
-    except Exception:
-        return np.asarray(x)
+    """Spill device arrays (an array or any pytree of them) to the host
+    memory tier: one ``device_put`` into each array's ``pinned_host``
+    memory (the jax memories API), which stays device-addressable and
+    round-trips bit-exactly.  A backend that cannot place it raises;
+    nothing falls back to a numpy copy."""
+    return jax.device_put(x, jax.tree.map(
+        lambda a: a.sharding.with_memory_kind("pinned_host"), x))
 
 
-def from_host_tier(x, like=None):
-    """Fetch a spilled array back toward HBM (inverse of ``to_host_tier``)."""
-    try:
-        if like is not None and hasattr(like, "sharding"):
-            return jax.device_put(x, like.sharding)
-        return jnp.asarray(x)
-    except Exception:
-        return jnp.asarray(x)
+def from_host_tier(x, like):
+    """Bring spilled arrays (an array or any pytree of them) back into the
+    device memory ``like`` lives in, in one ``device_put`` (inverse of
+    ``to_host_tier``)."""
+    return jax.device_put(x, like.sharding)
 
 
 # -- ring buffer for sliding-window layers -----------------------------------

@@ -172,7 +172,8 @@ class HostTier(PageTier):
     """Host-DRAM KV blobs, one per spilled page (pinned-host analogue).
 
     ``blobs[page]`` holds whatever the owner spilled — the serve engine
-    stores ``{layer: (k, v)}`` numpy pairs.  This is the placement target of
+    stores one ``(k, v)`` pair of ``pinned_host`` arrays holding the page
+    for every paged layer.  This is the placement target of
     the background flush: a demoted page gains a host copy here ("clean")
     without losing its device residency, so restore still repoints.
     """

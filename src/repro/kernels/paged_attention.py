@@ -9,16 +9,16 @@ the attention kernel, so no gathered KV copy is ever materialized in HBM.
 This is also where the paper's "small block I/O, large RDMA message"
 flexibility (§3.3) shows up on TPU: the *logical* page (tokens) is small for
 allocator granularity, while the *physical* DMA per grid step is a full
-page x head tile — large, aligned, WQE-cache-miss-free in TPU terms (few,
-big DMA descriptors).
+page with all of its KV heads — aligned to the pool's (Hkv, D) tiling,
+WQE-cache-miss-free in TPU terms (few, big DMA descriptors).
 
 Layout:
-  q:            (B, Hkv, G, D)   one token per sequence, grouped heads
+  q:            (B, G, Hkv, D)   one token per sequence, grouped heads
   k/v pool:     (n_slots, page, Hkv, D)
   block_table:  (B, P) int32 pool slot per logical page (-1 pad)
   lengths:      (B,)   valid token count per sequence
-Grid: (B, Hkv, P) with the page axis innermost/sequential; softmax state in
-VMEM scratch.
+Grid: (B, P) with the page axis innermost/sequential; softmax state per
+(group, KV head) in VMEM scratch.
 
 Zero-restore contract (PR 8): because the kernel reads KV *through* the
 block table, restoring a preempted sequence needs no bulk KV copy — the
@@ -43,9 +43,9 @@ NEG_INF = -1e30
 
 
 def _paged_kernel(block_table, lengths, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page, n_pages, scale):
+                  m_scr, l_scr, acc_scr, *, page, n_pages, group, scale):
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
@@ -58,74 +58,76 @@ def _paged_kernel(block_table, lengths, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(slot >= 0)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)         # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        k = k_ref[0].astype(jnp.float32)                  # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32)
         # token validity within the page (ragged tail)
-        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = pos < length
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[...]                               # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        pos = pi * page + jax.lax.broadcasted_iota(
+            jnp.int32, (page, k.shape[1], 1), 0)
+        mask = pos < length                               # (page, Hkv, 1)
+        # every KV head of the page at once: per-head scores are a lane
+        # reduction, so no head is ever sliced out of the (Hkv, D) tile
+        for g in range(group):
+            q = q_ref[0, g].astype(jnp.float32)           # (Hkv, D)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            s = jnp.where(mask, s, NEG_INF)               # (page, Hkv, 1)
+            m_prev = m_scr[g]                             # (Hkv, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.where(mask, jnp.exp(s - m_new[None]), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[g] = l_scr[g] * corr + jnp.sum(p, axis=0)
+            acc_scr[g] = acc_scr[g] * corr + jnp.sum(p * v, axis=0)
+            m_scr[g] = m_new
 
     @pl.when(pi == n_pages - 1)
     def _finish():
-        l = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        for g in range(group):
+            l = jnp.maximum(l_scr[g], 1e-20)
+            o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
                     interpret=False):
     """q: (B, Hq, D); pools: (n_slots, page, Hkv, D); block_table: (B, P).
 
-    Returns (B, Hq, D).  Pages with slot -1 are skipped (no DMA issued for
-    their compute; the safe slot-0 fetch is masked out).
+    Returns (B, Hq, D).  Pages with slot -1 are skipped (no compute; the
+    safe slot-0 fetch is masked out).  Each grid step DMAs one whole page,
+    all KV heads, so the block's trailing dims are the pool's ``(Hkv, D)``.
     """
     b, hq, d = q.shape
     n_slots, page, hkv, _ = k_pool.shape
     n_pages = block_table.shape[1]
     group = hq // hkv
-    qg = q.reshape(b, hkv, group, d)
+    # head h = kv * group + g; group-major so q_ref[0, g] is an (Hkv, D) tile
+    qg = q.reshape(b, hkv, group, d).transpose(0, 2, 1, 3)
     scale = 1.0 / math.sqrt(d)
 
     kernel = functools.partial(_paged_kernel, page=page, n_pages=n_pages,
-                               scale=scale)
-    grid = (b, hkv, n_pages)
+                               group=group, scale=scale)
 
-    def kv_index(bi, hi, pi, block_table, lengths):
+    def kv_index(bi, pi, block_table, lengths):
         slot = jnp.maximum(block_table[bi, pi], 0)        # pad -> slot 0
-        return (slot, 0, hi, 0)
+        return (slot, 0, 0, 0)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(b, n_pages),
             in_specs=[
-                pl.BlockSpec((1, 1, group, d),
-                             lambda bi, hi, pi, *refs: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, page, 1, d), kv_index),
-                pl.BlockSpec((1, page, 1, d), kv_index),
+                pl.BlockSpec((1, group, hkv, d),
+                             lambda bi, pi, *refs: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, page, hkv, d), kv_index),
+                pl.BlockSpec((1, page, hkv, d), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, 1, group, d),
-                                   lambda bi, hi, pi, *refs: (bi, hi, 0, 0)),
+            out_specs=pl.BlockSpec((1, group, hkv, d),
+                                   lambda bi, pi, *refs: (bi, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, d), jnp.float32),
+                pltpu.VMEM((group, hkv, 1), jnp.float32),
+                pltpu.VMEM((group, hkv, 1), jnp.float32),
+                pltpu.VMEM((group, hkv, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, group, hkv, d), q.dtype),
         interpret=interpret,
     )(block_table, lengths, qg, k_pool, v_pool)
-    return out.reshape(b, hq, d)
+    return out.transpose(0, 2, 1, 3).reshape(b, hq, d)

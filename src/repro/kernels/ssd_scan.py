@@ -37,28 +37,35 @@ def _ssd_kernel(A_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
     bmat = b_ref[0, 0, 0].astype(jnp.float32)            # (Q, N)
     cmat = c_ref[0, 0, 0].astype(jnp.float32)            # (Q, N)
 
-    l = dt[:, 0] * a                                     # (Q,) log decays
-    lc = jnp.cumsum(l)                                   # within-chunk cumsum
-    ltot = lc[chunk - 1]
+    # Within-chunk cumulative log decays as masked sums over the chunk
+    # (Mosaic has no cumsum), in both orientations the math below needs:
+    # lc[t] = sum_{s<=t} l_s as (Q, 1) and the same as lc_row (1, Q).  dt moves
+    # from sublanes to lanes by a diagonal-masked reduction, which is exact.
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    dt_row = jnp.sum(jnp.where(ti == si, dt, 0.0), axis=0, keepdims=True)
+    l_col = dt * a                                       # (Q, 1) log decays
+    l_row = dt_row * a                                   # (1, Q)
+    lc = jnp.sum(jnp.where(si <= ti, l_row, 0.0), axis=1, keepdims=True)
+    lc_row = jnp.sum(jnp.where(ti <= si, l_col, 0.0), axis=0, keepdims=True)
+    ltot = jnp.sum(l_col, axis=0, keepdims=True)         # (1, 1)
 
     # intra-chunk: y[t] = sum_{s<=t} (C_t.B_s) exp(lc_t - lc_s) dt_s x_s
     cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (Q,Q)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(lc[:, None] - lc[None, :])
-    m = jnp.where(ti >= si, cb * decay, 0.0) * dt[None, :, 0]
+    decay = jnp.exp(lc - lc_row)
+    m = jnp.where(ti >= si, cb * decay, 0.0) * dt_row
     y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: y[t] += C_t . (exp(lc_t) * h_prev)
     h_prev = h_scr[...]                                  # (P, N)
-    y = y + jnp.exp(lc)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(lc) * jax.lax.dot_general(
         cmat, h_prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update: h = exp(ltot) * h_prev + sum_s exp(ltot - lc_s) dt_s x_s B_s^T
-    w = (jnp.exp(ltot - lc) * dt[:, 0])[:, None] * x     # (Q, P)
+    w = (jnp.exp(ltot - lc) * dt) * x                    # (Q, P)
     h_new = jnp.exp(ltot) * h_prev + jax.lax.dot_general(
         w, bmat, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)              # (P, N)

@@ -6,13 +6,20 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the models place activations with
+    ``with_sharding_constraint``, which only accepts Auto axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (2 pods, 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_degraded_mesh(n_alive: int, model_parallel: int = 16):
@@ -28,7 +35,7 @@ def make_degraded_mesh(n_alive: int, model_parallel: int = 16):
 
 def make_local_mesh(dp: int = 1, mp: int = 1):
     """Small mesh over whatever devices exist (tests)."""
-    return jax.make_mesh((dp, mp), ("data", "model"))
+    return _auto_mesh((dp, mp), ("data", "model"))
 
 
 def mesh_axes(mesh):

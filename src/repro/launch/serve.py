@@ -3,6 +3,10 @@
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-4b --local \
         --requests 8 --policy valet --pool-slots 16
 
+Without ``--local`` the model runs at its published widths in bfloat16
+(weights from a seed, nothing is downloaded); ``--local`` serves the
+reduced float32 config, which fits a CPU.
+
 ``--dryrun`` lowers+compiles the sharded serve_step for the production mesh
 (same path the dry-run sweep uses).
 """
@@ -10,6 +14,28 @@ from __future__ import annotations
 
 import argparse
 import os
+
+
+def build_model(arch: str, *, local: bool):
+    """``(cfg, ctx, params)`` for serving ``arch``, weights from seed 0.
+
+    Full width: bfloat16 params and compute.  ``local``: the reduced config
+    in float32.  Params are built under ``jax.jit`` so a segment's layers
+    are generated straight into their stacked buffer, never held twice."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_arch, reduced
+    from repro.models import transformer as T
+
+    if local:
+        cfg, dtype = reduced(get_arch(arch)), jnp.float32
+        ctx = T.ParallelCtx(remat=False, q_block=16, kv_block=16)
+    else:
+        cfg, dtype = get_arch(arch), jnp.bfloat16
+        ctx = T.ParallelCtx(remat=False, compute_dtype=dtype)
+    init = jax.jit(T.init_params, static_argnames=("cfg", "dtype"))
+    params = init(jax.random.PRNGKey(0), cfg, dtype)
+    return cfg, ctx, params
 
 
 def main():
@@ -37,15 +63,12 @@ def main():
         return 0 if rec.get("status") == "ok" else 1
 
     import numpy as np
-    import jax
-    from repro.configs import get_arch, reduced
     from repro.core.policies import POLICIES
-    from repro.models import transformer as T
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import ValetServeEngine
 
-    cfg = reduced(get_arch(args.arch)) if args.local else get_arch(args.arch)
-    ctx = T.ParallelCtx(remat=False, q_block=16, kv_block=16)
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    enable_compile_cache()
+    cfg, ctx, params = build_model(args.arch, local=args.local)
     eng = ValetServeEngine(
         params, cfg, ctx, max_batch=args.max_batch,
         max_seq=args.prompt_len + args.max_new + args.page,

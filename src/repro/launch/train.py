@@ -5,15 +5,15 @@
     PYTHONPATH=src python -m repro.launch.train --arch granite-3-8b \
         --dryrun                       # lower+compile on the production mesh
 
-On a real TPU pod this module is the per-host entry point: jax.distributed
-initializes from the TPU environment, every host builds the same mesh and
-feeds its deterministic data shard (repro.data), checkpoints flow through
-ValetCheckpointer, and recovery uses train.elastic.
+On one host with several devices (four TPU chips, say) the step runs on a
+(data, model) mesh over all of them: model 2 when the device count is
+even, data over the rest.  Checkpoints flow through ValetCheckpointer.
 """
 from __future__ import annotations
 
 import argparse
 import os
+from functools import partial
 
 
 def main():
@@ -39,7 +39,8 @@ def main():
     from repro.configs import get_arch, reduced
     from repro.data import DataConfig, TrainDataset
     from repro.models import transformer as T
-    from repro.train import (TrainConfig, ValetCheckpointer, fit)
+    from repro.train import (TrainConfig, ValetCheckpointer, fit,
+                             make_shardings)
 
     if args.dryrun:
         from repro.launch.dryrun import run_cell, _artifact_dir
@@ -50,13 +51,26 @@ def main():
         return 0 if rec.get("status") == "ok" else 1
 
     cfg = reduced(get_arch(args.arch)) if args.local else get_arch(args.arch)
-    ctx = T.ParallelCtx(remat=False, q_block=32, kv_block=32, loss_chunk=32,
-                        compute_dtype=jnp.float32)
+    mesh = None
+    n_dev = jax.device_count()
+    if n_dev > 1:
+        from repro.launch.mesh import make_local_mesh
+        mp = 2 if n_dev % 2 == 0 else 1
+        mesh = make_local_mesh(n_dev // mp, mp)
+    ctx = T.ParallelCtx(mesh=mesh, remat=False, q_block=32, kv_block=32,
+                        loss_chunk=32, compute_dtype=jnp.float32)
     tcfg = TrainConfig(
         microbatches=args.microbatches, compute_dtype=jnp.float32,
         adamw=optim.AdamWConfig(lr=args.lr, warmup_steps=10,
                                 total_steps=args.steps))
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    key = jax.random.PRNGKey(0)
+    init = partial(T.init_params, cfg=cfg)
+    if mesh is None:
+        params = jax.jit(init)(key)
+    else:
+        # generated straight into their shardings, never whole on one device
+        ins, _ = make_shardings(cfg, ctx, tcfg, jax.eval_shape(init, key))
+        params = jax.jit(init, out_shardings=ins[0])(key)
     ds = TrainDataset(DataConfig(vocab=cfg.vocab, seq_len=args.seq_len,
                                  global_batch=args.global_batch))
     ckpt = ValetCheckpointer(args.ckpt_dir, replicas=2)

@@ -129,16 +129,10 @@ def _dispatch_local(x, eids, gates, wg, wu, wd, *, e_base, e_local, cap):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map wrapper (location + check_vma/check_rep)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:                         # jax < 0.5: experimental namespace
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` without the replication check (the bodies use
+    collectives whose replication the checker cannot infer)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def moe_ffn(params, x, moe: MoEConfig, *, mesh=None, model_axis="model",

@@ -205,8 +205,12 @@ def init_layer(kg, cfg: ArchConfig, seg: Segment, dtype=jnp.float32):
     return p
 
 
-def _stack(trees):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+def _init_stack(kg, cfg: ArchConfig, seg: Segment, dtype):
+    """A segment's ``seg.count`` layers, generated directly in their stacked
+    layout (one key per layer, vmapped): the per-layer trees never exist
+    beside the stack, and a jitted init compiles one layer, not all."""
+    keys = jax.random.split(kg(), seg.count)
+    return jax.vmap(lambda k: init_layer(KeyGen(k), cfg, seg, dtype))(keys)
 
 
 def init_params(key, cfg: ArchConfig, dtype=jnp.float32):
@@ -215,19 +219,15 @@ def init_params(key, cfg: ArchConfig, dtype=jnp.float32):
     params = {
         "embed": normal_init(kg(), (cfg.padded_vocab, d), dtype=dtype),
         "final_ln": jnp.zeros((d,), dtype),
-        "segments": [
-            _stack([init_layer(kg, cfg, seg, dtype) for _ in range(seg.count)])
-            for seg in segments(cfg)
-        ],
+        "segments": [_init_stack(kg, cfg, seg, dtype)
+                     for seg in segments(cfg)],
     }
     if not cfg.tie_embeddings:
         params["unembed"] = normal_init(kg(), (d, cfg.padded_vocab),
                                         dtype=dtype)
     if cfg.family == "audio":
-        params["enc_segments"] = [
-            _stack([init_layer(kg, cfg, seg, dtype) for _ in range(seg.count)])
-            for seg in encoder_segments(cfg)
-        ]
+        params["enc_segments"] = [_init_stack(kg, cfg, seg, dtype)
+                                  for seg in encoder_segments(cfg)]
         params["enc_ln"] = jnp.zeros((d,), dtype)
     return params
 

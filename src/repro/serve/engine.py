@@ -21,8 +21,9 @@ The engine is the paper's sender node in serving clothes:
 block table (``kernels/paged_attention.py``), restore needs no bulk copy:
 ``_restore`` repoints block-table entries at pool slots whose bytes survived
 preemption untouched (validated against the pool's per-slot generation
-counter) and streams only the pages whose slot was reused in the meantime,
-one ``device_ops.stream_page`` host read each.  The legacy bulk per-layer
+counter) and streams only the pages whose slot was reused in the meantime:
+one transfer back from the host tier for all of them, then one
+``device_ops.stream_page`` scatter each.  The legacy bulk per-layer
 ``local_write_batch`` scatter and the ad-hoc ``host_store`` dict are gone
 from the restore critical path; the host blobs live in a first-class
 ``HostTier`` fed by the background flush.  ``zero_restore=False`` keeps the
@@ -140,7 +141,7 @@ class ValetServeEngine:
         self.paged_layers = [i for i, inf in enumerate(self.infos)
                              if inf.uses_paged]
         self.caches = D.init_caches(cfg, max_batch, pool_slots=pool_slots,
-                                    page=page)
+                                    page=page, dtype=ctx.compute_dtype)
         # multi-tenant serving (§3.4): K engines register with one
         # HostMemoryCoordinator, each leasing KV-pool pages on demand and
         # donating FREE slots back when a co-located engine is under
@@ -259,8 +260,9 @@ class ValetServeEngine:
         key = s
         if key not in self._prefill_jit:
             def fn(params, caches, toks, bt):
-                one = D.init_caches(self.cfg, 1,
-                                    pool_slots=1, page=self.page)
+                one = D.init_caches(self.cfg, 1, pool_slots=1,
+                                    page=self.page,
+                                    dtype=self.ctx.compute_dtype)
                 # share the batched pools: prefill writes pages directly
                 for li, c in enumerate(one["layers"]):
                     if "pool" in c:
@@ -306,15 +308,8 @@ class ValetServeEngine:
             return
         dirty = [(pg, sl) for pg, sl in pairs if pg not in self.host]
         if dirty:
-            idx = jnp.asarray(np.asarray([sl for _, sl in dirty], np.int32))
-            layer_kv = {}
-            for li in self.paged_layers:
-                pool = self.caches["layers"][li]["pool"]
-                layer_kv[li] = (dev.to_host_tier(pool.k[idx]),
-                                dev.to_host_tier(pool.v[idx]))
-            for i, (pg, _) in enumerate(dirty):
-                self.host.put(pg, {li: (kv[0][i], kv[1][i])
-                                   for li, kv in layer_kv.items()})
+            self._pages_to_host([pg for pg, _ in dirty],
+                                [sl for _, sl in dirty])
             self.stats.sim_time_us += self.costs.host_write * len(dirty)
             self.stats.flushed_pages += len(dirty)
         # every evicted page is host-resident now: retier DEVICE -> HOST
@@ -322,6 +317,25 @@ class ValetServeEngine:
         m = int(parr.size)
         self.gpt.map_remote_batch(parr, [int(Tier.HOST)] * m,
                                   [-1] * m, [-1] * m, None)
+
+    def _paged_pools(self) -> List[dev.KVPool]:
+        return [self.caches["layers"][li]["pool"] for li in self.paged_layers]
+
+    def _set_paged_pools(self, pools) -> None:
+        for li, pool in zip(self.paged_layers, pools):
+            self.caches["layers"][li]["pool"] = pool
+
+    def _pages_to_host(self, pages, slots) -> None:
+        """Put a host-tier copy of each page in ``pages`` (resident in the
+        matching pool ``slots``).  The pages are read on the device across
+        every paged layer in one dispatch, so only whole per-page arrays
+        cross to ``pinned_host``, in one transfer; a page's blob is its
+        ``(k, v)`` pair."""
+        pools = self._paged_pools()
+        blobs = (dev.to_host_tier(dev.read_pages(pools, slots)) if pools
+                 else [()] * len(pages))
+        for pg, blob in zip(pages, blobs):
+            self.host.put(pg, blob)
 
     def _flush_demoted(self, budget: Optional[int] = None) -> int:
         """Background write-back daemon: secure host copies for up to
@@ -345,15 +359,7 @@ class ValetServeEngine:
                 slots.append(sl)
         if not todo:
             return 0
-        idx = jnp.asarray(np.asarray(slots, np.int32))
-        layer_kv = {}
-        for li in self.paged_layers:
-            pool = self.caches["layers"][li]["pool"]
-            layer_kv[li] = (dev.to_host_tier(pool.k[idx]),
-                            dev.to_host_tier(pool.v[idx]))
-        for i, pg in enumerate(todo):
-            self.host.put(pg, {li: (kv[0][i], kv[1][i])
-                               for li, kv in layer_kv.items()})
+        self._pages_to_host(todo, slots)
         m = len(todo)
         self.stats.flushed_pages += m
         cost = self.costs.host_write * m
@@ -496,14 +502,19 @@ class ValetServeEngine:
         if slots is None:           # cannot happen: free_count checked above
             raise RuntimeError(f"pool refused batch of {n} restore pages")
         blobs = [self.host.pop(pg) for pg in needed_l]
-        idx = jnp.asarray(np.asarray(slots, np.int32))
-        for li in self.paged_layers:
-            ks = jnp.asarray(np.stack([np.asarray(b[li][0]) for b in blobs]))
-            vs = jnp.asarray(np.stack([np.asarray(b[li][1]) for b in blobs]))
+        pools = self._paged_pools()
+        if pools:
+            idx = jnp.asarray(np.asarray(slots, np.int32))
+            # back in device memory in one transfer, then
+            # (n, n_layers, page, n_kv, hd)
+            blobs = dev.from_host_tier(blobs, pools[0].k)
+            ks = jnp.stack([b[0] for b in blobs])
+            vs = jnp.stack([b[1] for b in blobs])
             # one whole-page scatter per paged layer via the shared bulk
             # data-plane primitive (the same one fill/write allocs ride)
-            self.caches["layers"][li]["pool"] = dev.local_write_batch(
-                self.caches["layers"][li]["pool"], ks, vs, idx)
+            self._set_paged_pools(
+                dev.local_write_batch(pool, ks[:, j], vs[:, j], idx)
+                for j, pool in enumerate(pools))
         self.gpt.map_local_batch(needed, np.asarray(slots, np.int64))
         self.gpt.drop_remote_batch(needed)
         self.tracker.on_write(needed_l, self.step_counter)
@@ -541,12 +552,14 @@ class ValetServeEngine:
             if slots is None:       # cannot happen: free_count checked above
                 raise RuntimeError(f"pool refused batch of {k} stream pages")
             self._note_allocated(slots)
-            for pg, sl in zip(stream, slots):
-                blob = self.host.pop(pg)
-                for li in self.paged_layers:
-                    self.caches["layers"][li]["pool"] = dev.stream_page(
-                        self.caches["layers"][li]["pool"],
-                        blob[li][0], blob[li][1], sl)
+            blobs = [self.host.pop(pg) for pg in stream]
+            pools = self._paged_pools()
+            if pools:
+                # every streamed page back in device memory in one transfer
+                for (k_pg, v_pg), sl in zip(
+                        dev.from_host_tier(blobs, pools[0].k), slots):
+                    pools = dev.stream_page(pools, k_pg, v_pg, sl)
+                self._set_paged_pools(pools)
             self.gpt.map_local_batch(np.asarray(stream, np.int64),
                                      np.asarray(slots, np.int64))
             self.stats.streamed_pages += k
@@ -821,17 +834,9 @@ class ValetServeEngine:
             self.stats.demoted_pages += m
             self.stats.spilled_pages += m
         elif live.size:
-            # legacy bulk spill: one gather + host transfer per paged layer,
-            # then grouped release / unmap / remote-map
-            idx = jnp.asarray(live_slots.astype(np.int32))
-            layer_kv = {}
-            for li in self.paged_layers:
-                pool = self.caches["layers"][li]["pool"]
-                layer_kv[li] = (dev.to_host_tier(pool.k[idx]),
-                                dev.to_host_tier(pool.v[idx]))
-            for i, pg in enumerate(live.tolist()):
-                self.host.put(pg, {li: (kv[0][i], kv[1][i])
-                                   for li, kv in layer_kv.items()})
+            # legacy eager spill: every page to the host tier now, then
+            # grouped release / unmap / remote-map
+            self._pages_to_host(live.tolist(), live_slots.tolist())
             self.pool.release_batch(live_slots.tolist())
             self.gpt.unmap_local_batch(live)
             m = int(live.size)

@@ -119,9 +119,22 @@ def make_shardings(cfg: ArchConfig, ctx: T.ParallelCtx, tcfg: TrainConfig,
 
 def fit(params, cfg: ArchConfig, ctx: T.ParallelCtx, tcfg: TrainConfig,
         dataset, n_steps: int, log_every: int = 10, callback=None):
-    """Simple single-host fit loop (examples / integration tests)."""
-    step_fn = jax.jit(make_train_step(cfg, ctx, tcfg))
-    opt_state = optim.init(params)
+    """Simple single-host fit loop (examples / integration tests).  With
+    ``ctx.mesh`` the step, params and optimizer state are sharded over it
+    (``make_shardings``): params are moved to their shardings (a no-op for
+    params built there, as ``launch/train.py`` does) and the optimizer
+    moments are made sharded, never whole on one device.  Without a mesh
+    everything stays on one device."""
+    step = make_train_step(cfg, ctx, tcfg)
+    if ctx.mesh is None:
+        step_fn = jax.jit(step)
+        opt_state = optim.init(params)
+    else:
+        ins, outs = make_shardings(cfg, ctx, tcfg,
+                                   jax.eval_shape(lambda: params))
+        step_fn = jax.jit(step, in_shardings=ins, out_shardings=outs)
+        params = jax.device_put(params, ins[0])
+        opt_state = jax.jit(optim.init, out_shardings=ins[1])(params)
     history = []
     n_micro = tcfg.microbatches
     for i, (tokens, labels) in zip(range(n_steps), dataset):

@@ -49,7 +49,8 @@ SCRIPT = textwrap.dedent("""
     p1, o1, m1 = jax.jit(step1)(params, opt, toks, labels)
 
     # 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ctx2 = T.ParallelCtx(mesh=mesh, dp_axes=("data",), remat=False,
                          q_block=16, kv_block=16, loss_chunk=16,
                          compute_dtype=jnp.float32)
@@ -168,6 +169,70 @@ SCRIPT = textwrap.dedent("""
     assert err8 < 0.08, err8
     print("DECODE_INT8_OK", err8)
 """).replace("SRC", os.path.join(os.path.dirname(__file__), "..", "src"))
+
+
+FIT_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys
+    sys.path.insert(0, "SRC")
+    import jax, jax.numpy as jnp
+
+    from repro import optim
+    from repro.configs import get_arch, reduced
+    from repro.data import DataConfig, TrainDataset
+    from repro.launch import train as launch_train
+    from repro.launch.mesh import make_local_mesh
+    from repro.models import transformer as T
+    from repro.train import TrainConfig, fit
+
+    assert jax.device_count() == 4
+    cfg = reduced(get_arch("phi3-mini-3.8b"))
+    tcfg = TrainConfig(microbatches=2, compute_dtype=jnp.float32,
+                       adamw=optim.AdamWConfig(lr=1e-3))
+    blocks = dict(remat=False, q_block=16, kv_block=16, loss_chunk=16,
+                  compute_dtype=jnp.float32)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    data = lambda: TrainDataset(DataConfig(vocab=cfg.vocab, seq_len=32,
+                                           global_batch=8))
+
+    ctx4 = T.ParallelCtx(mesh=make_local_mesh(2, 2), **blocks)
+    p4, o4, h4 = fit(params, cfg, ctx4, tcfg, data(), n_steps=3, log_every=1)
+    p1, o1, h1 = fit(params, cfg, T.ParallelCtx(**blocks), tcfg, data(),
+                     n_steps=3, log_every=1)
+    # the state lives on the mesh, and the embedding and its moments are
+    # split over it, not whole on one device
+    for a in jax.tree.leaves((p4, o4)):
+        assert len(a.sharding.device_set) == 4, a.sharding
+    for a in (p4["embed"], o4.mu["embed"], o4.nu["embed"]):
+        assert a.addressable_shards[0].data.size < a.size, a.sharding
+    for a, b in zip(h1, h4):
+        assert abs(a["loss"] - b["loss"]) < 1e-4, (h1, h4)
+    d = max(jax.tree.leaves(jax.tree.map(
+        lambda a, b: float(jnp.abs(a - b).max()), p1, p4)))
+    assert d < 1e-3, d
+    print("FIT_OK", [h["loss"] for h in h4], d)
+
+    # the launcher's own path: a (2, 2) mesh and params built sharded
+    sys.argv = ["train", "--arch", "phi3-mini-3.8b", "--local", "--steps",
+                "2", "--seq-len", "32", "--ckpt-dir", "CKPT"]
+    assert launch_train.main() == 0
+    print("LAUNCH_OK")
+""").replace("SRC", os.path.join(os.path.dirname(__file__), "..", "src"))
+
+
+def test_fit_on_four_devices_matches_one_device(tmp_path):
+    """``fit`` over a (data 2, model 2) mesh of virtual CPU devices keeps
+    params and optimizer state sharded and agrees with one device; the
+    training launcher runs on that mesh."""
+    script = tmp_path / "fit_check.py"
+    script.write_text(FIT_SCRIPT.replace("CKPT", str(tmp_path / "ckpt")))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    res = subprocess.run([sys.executable, str(script)], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert "FIT_OK" in res.stdout
+    assert "LAUNCH_OK" in res.stdout
 
 
 @pytest.mark.slow
