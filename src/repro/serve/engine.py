@@ -61,6 +61,7 @@ from repro.core.reservoir import LatencyStatsMixin
 from repro.core.tiers import DeviceTier, HostTier
 from repro.models import decode as D
 from repro.models.transformer import ParallelCtx
+from repro.serve.tracing import OFF, Tracer
 
 
 @dataclass
@@ -205,7 +206,10 @@ class ValetServeEngine:
         self._seq_blobs: Dict[int, Any] = {}
 
         self._decode_jit = jax.jit(self._decode_fn)
-        self._prefill_jit = {}
+        self._prefill_jit = jax.jit(self._prefill_fn)
+        self._prefill_lengths: set = set()
+        # the engine's spans, off until ``tracer.start()`` (serve/tracing.py)
+        self.tracer = Tracer()
 
     @property
     def host_store(self) -> Dict[int, dict]:
@@ -253,27 +257,28 @@ class ValetServeEngine:
         return D.decode_step(params, caches, tokens, self.cfg, self.ctx,
                              bt, app_slot, app_off, active=active)
 
+    def _prefill_fn(self, params, caches, toks, bt):
+        one = D.init_caches(self.cfg, 1, pool_slots=1, page=self.page,
+                            dtype=self.ctx.compute_dtype)
+        # share the batched pools: prefill writes pages directly
+        for li, c in enumerate(one["layers"]):
+            if "pool" in c:
+                c["pool"] = caches["layers"][li]["pool"]
+        return D.prefill(params, toks, self.cfg, self.ctx, one, bt)
+
     def _prefill_one(self, prompt_tokens: np.ndarray, slot: int,
                      bt_row: np.ndarray):
-        """Prefill one request (B=1) and scatter results into batch caches."""
+        """Prefill one request (B=1) and scatter results into batch caches.
+        The prefill program compiles once for each prompt length."""
         s = len(prompt_tokens)
-        key = s
-        if key not in self._prefill_jit:
-            def fn(params, caches, toks, bt):
-                one = D.init_caches(self.cfg, 1, pool_slots=1,
-                                    page=self.page,
-                                    dtype=self.ctx.compute_dtype)
-                # share the batched pools: prefill writes pages directly
-                for li, c in enumerate(one["layers"]):
-                    if "pool" in c:
-                        c["pool"] = caches["layers"][li]["pool"]
-                logits, one = D.prefill(params, toks, self.cfg, self.ctx,
-                                        one, bt)
-                return logits, one
-            self._prefill_jit[key] = jax.jit(fn)
+        fresh = s not in self._prefill_lengths
+        self._prefill_lengths.add(s)
         bt_j = jnp.asarray(bt_row)[None]
-        logits, one = self._prefill_jit[key](
-            self.params, self.caches, jnp.asarray(prompt_tokens)[None], bt_j)
+        tr = self.tracer
+        with (tr.span("prefill.compile", n=s) if fresh and tr.on else OFF):
+            logits, one = self._prefill_jit(
+                self.params, self.caches, jnp.asarray(prompt_tokens)[None],
+                bt_j)
 
         # scatter per-seq cache entries into the batch slot
         for li, (bc, oc) in enumerate(zip(self.caches["layers"],
@@ -308,8 +313,10 @@ class ValetServeEngine:
             return
         dirty = [(pg, sl) for pg, sl in pairs if pg not in self.host]
         if dirty:
-            self._pages_to_host([pg for pg, _ in dirty],
-                                [sl for _, sl in dirty])
+            tr = self.tracer
+            with (tr.span("flush", n=len(dirty)) if tr.on else OFF):
+                self._pages_to_host([pg for pg, _ in dirty],
+                                    [sl for _, sl in dirty])
             self.stats.sim_time_us += self.costs.host_write * len(dirty)
             self.stats.flushed_pages += len(dirty)
         # every evicted page is host-resident now: retier DEVICE -> HOST
@@ -332,10 +339,12 @@ class ValetServeEngine:
         cross to ``pinned_host``, in one transfer; a page's blob is its
         ``(k, v)`` pair."""
         pools = self._paged_pools()
-        blobs = (dev.to_host_tier(dev.read_pages(pools, slots)) if pools
-                 else [()] * len(pages))
-        for pg, blob in zip(pages, blobs):
-            self.host.put(pg, blob)
+        tr = self.tracer
+        with (tr.span("to_host", n=len(pages)) if tr.on else OFF):
+            blobs = (dev.to_host_tier(dev.read_pages(pools, slots)) if pools
+                     else [()] * len(pages))
+            for pg, blob in zip(pages, blobs):
+                self.host.put(pg, blob)
 
     def _flush_demoted(self, budget: Optional[int] = None) -> int:
         """Background write-back daemon: secure host copies for up to
@@ -359,8 +368,10 @@ class ValetServeEngine:
                 slots.append(sl)
         if not todo:
             return 0
-        self._pages_to_host(todo, slots)
         m = len(todo)
+        tr = self.tracer
+        with (tr.span("flush", n=m) if tr.on else OFF):
+            self._pages_to_host(todo, slots)
         self.stats.flushed_pages += m
         cost = self.costs.host_write * m
         if self.async_mode:
@@ -448,26 +459,28 @@ class ValetServeEngine:
 
     def _make_room(self, n_pages: int) -> bool:
         """Policy-driven preemption to free >= n_pages pool slots."""
-        victims_order = sorted(
-            [r for r in self._requests.values() if r.status == "active"],
-            key=lambda r: r.last_active_step)
-        freed = 0
-        while self.pool.free_count() < n_pages and victims_order:
-            if self.policy.evict_action == "migrate":
-                victim = victims_order.pop(0)      # NAD: least recently active
-            elif self.policy.victim == "random":
-                victim = victims_order.pop(
-                    int(self.rng.integers(len(victims_order))))
-            else:
-                victim = victims_order.pop(0)
-            freed += self._preempt(victim)
-        if self._zero and freed:
-            # the freed slots are about to be handed out: flush the newly
-            # demoted pages now so the reuse finds them clean (the write-
-            # back overlaps the admit/prefill compute — still off the
-            # critical path, like the paper's lazy sender)
-            self._flush_demoted(None)
-        return self.pool.free_count() >= n_pages
+        tr = self.tracer
+        with (tr.span("make_room") if tr.on else OFF):
+            victims_order = sorted(
+                [r for r in self._requests.values() if r.status == "active"],
+                key=lambda r: r.last_active_step)
+            freed = 0
+            while self.pool.free_count() < n_pages and victims_order:
+                if self.policy.evict_action == "migrate":
+                    victim = victims_order.pop(0)  # NAD: least recently active
+                elif self.policy.victim == "random":
+                    victim = victims_order.pop(
+                        int(self.rng.integers(len(victims_order))))
+                else:
+                    victim = victims_order.pop(0)
+                freed += self._preempt(victim)
+            if self._zero and freed:
+                # the freed slots are about to be handed out: flush the
+                # newly demoted pages now so the reuse finds them clean (the
+                # write-back overlaps the admit/prefill compute — still off
+                # the critical path, like the paper's lazy sender)
+                self._flush_demoted(None)
+            return self.pool.free_count() >= n_pages
 
     def _restore(self, req: Request) -> bool:
         """Bring a paused sequence's pages back into the pool.
@@ -490,37 +503,39 @@ class ValetServeEngine:
         if self.pool.free_count() < n:
             if not self._reserve(n):
                 return False
-        needed_l = needed.tolist()
-        if self._zero:
-            return self._restore_zero(needed, needed_l, n)
-        if self.async_mode:
-            # the spill daemon may still be writing these bytes out: a
-            # restore is a true data dependency, so it fences — waits out
-            # the daemon's in-flight work — before reading them back
-            self._fence()
-        slots = self.pool.alloc_batch(needed_l, [self.step_counter] * n)
-        if slots is None:           # cannot happen: free_count checked above
-            raise RuntimeError(f"pool refused batch of {n} restore pages")
-        blobs = [self.host.pop(pg) for pg in needed_l]
-        pools = self._paged_pools()
-        if pools:
-            idx = jnp.asarray(np.asarray(slots, np.int32))
-            # back in device memory in one transfer, then
-            # (n, n_layers, page, n_kv, hd)
-            blobs = dev.from_host_tier(blobs, pools[0].k)
-            ks = jnp.stack([b[0] for b in blobs])
-            vs = jnp.stack([b[1] for b in blobs])
-            # one whole-page scatter per paged layer via the shared bulk
-            # data-plane primitive (the same one fill/write allocs ride)
-            self._set_paged_pools(
-                dev.local_write_batch(pool, ks[:, j], vs[:, j], idx)
-                for j, pool in enumerate(pools))
-        self.gpt.map_local_batch(needed, np.asarray(slots, np.int64))
-        self.gpt.drop_remote_batch(needed)
-        self.tracker.on_write(needed_l, self.step_counter)
-        self.stats.restored_pages += n
-        self.stats.sim_time_us += self.costs.host_read * n
-        return True
+        tr = self.tracer
+        with (tr.span("restore", req.rid, n) if tr.on else OFF):
+            needed_l = needed.tolist()
+            if self._zero:
+                return self._restore_zero(needed, needed_l, n)
+            if self.async_mode:
+                # the spill daemon may still be writing these bytes out: a
+                # restore is a true data dependency, so it fences — waits out
+                # the daemon's in-flight work — before reading them back
+                self._fence()
+            slots = self.pool.alloc_batch(needed_l, [self.step_counter] * n)
+            if slots is None:   # cannot happen: free_count checked above
+                raise RuntimeError(f"pool refused batch of {n} restore pages")
+            blobs = [self.host.pop(pg) for pg in needed_l]
+            pools = self._paged_pools()
+            if pools:
+                idx = jnp.asarray(np.asarray(slots, np.int32))
+                # back in device memory in one transfer, then
+                # (n, n_layers, page, n_kv, hd)
+                blobs = dev.from_host_tier(blobs, pools[0].k)
+                ks = jnp.stack([b[0] for b in blobs])
+                vs = jnp.stack([b[1] for b in blobs])
+                # one whole-page scatter per paged layer via the shared bulk
+                # data-plane primitive (the same one fill/write allocs ride)
+                self._set_paged_pools(
+                    dev.local_write_batch(pool, ks[:, j], vs[:, j], idx)
+                    for j, pool in enumerate(pools))
+            self.gpt.map_local_batch(needed, np.asarray(slots, np.int64))
+            self.gpt.drop_remote_batch(needed)
+            self.tracker.on_write(needed_l, self.step_counter)
+            self.stats.restored_pages += n
+            self.stats.sim_time_us += self.costs.host_read * n
+            return True
 
     def _restore_zero(self, needed: np.ndarray, needed_l: List[int],
                       n: int) -> bool:
@@ -555,10 +570,13 @@ class ValetServeEngine:
             blobs = [self.host.pop(pg) for pg in stream]
             pools = self._paged_pools()
             if pools:
+                tr = self.tracer
                 # every streamed page back in device memory in one transfer
-                for (k_pg, v_pg), sl in zip(
-                        dev.from_host_tier(blobs, pools[0].k), slots):
-                    pools = dev.stream_page(pools, k_pg, v_pg, sl)
+                with (tr.span("from_host", n=k) if tr.on else OFF):
+                    back = dev.from_host_tier(blobs, pools[0].k)
+                with (tr.span("stream", n=k) if tr.on else OFF):
+                    for (k_pg, v_pg), sl in zip(back, slots):
+                        pools = dev.stream_page(pools, k_pg, v_pg, sl)
                 self._set_paged_pools(pools)
             self.gpt.map_local_batch(np.asarray(stream, np.int64),
                                      np.asarray(slots, np.int64))
@@ -589,61 +607,69 @@ class ValetServeEngine:
     def _admit(self, req: Request) -> bool:
         if not self._slots_free:
             return False
-        need = self._pages_for(len(req.prompt) + 1)
-        if self.pool.free_count() < need and not self._reserve(need):
-            return False
-        req.slot = self._slots_free.pop()
-        if not self._alloc_pages(req, need):
-            raise RuntimeError(f"admit: failed to allocate {need} pages")
-        bt = self._block_table_row(req)
-        logits = self._prefill_one(req.prompt, req.slot, bt)
-        # the prompt's last position yields the first generated token
-        req.tokens_out.append(int(jnp.argmax(logits[0])))
-        self.stats.tokens += 1
-        self.stats.sim_time_us += self.costs.local_write * need
-        if req.first_token_us < 0:
-            req.first_token_us = self.stats.sim_time_us
-        req.status = "active"
-        req.last_active_step = self.step_counter
-        if len(req.tokens_out) >= req.max_new:
-            req.status = "done"
-            self._slots_free.append(req.slot)
-            self._free_pages(req)
-            req.slot = -1
-        return True
-
-    def _resume(self, req: Request) -> bool:
-        if not self._slots_free:
-            return False
-        if self.policy.evict_action == "delete" or not req.pages:
-            # pages were deleted: re-prefill prompt + generated tokens,
-            # EXCLUDING the newest one — the next decode step consumes it
-            full = np.concatenate([req.prompt,
-                                   np.asarray(req.tokens_out[:-1], np.int64)])
-            need = self._pages_for(len(full) + 1)
+        tr = self.tracer
+        with (tr.span("admit", req.rid) if tr.on else OFF):
+            need = self._pages_for(len(req.prompt) + 1)
             if self.pool.free_count() < need and not self._reserve(need):
                 return False
             req.slot = self._slots_free.pop()
             if not self._alloc_pages(req, need):
-                raise RuntimeError(f"resume: failed to allocate {need} pages")
-            self._prefill_one(full, req.slot, self._block_table_row(req))
-            self.stats.recomputes += 1
-            self.stats.sim_time_us += self.costs.cold_read * need
+                raise RuntimeError(f"admit: failed to allocate {need} pages")
+            bt = self._block_table_row(req)
+            with (tr.span("prefill", req.rid, len(req.prompt)) if tr.on
+                  else OFF):
+                logits = self._prefill_one(req.prompt, req.slot, bt)
+                # the prompt's last position yields the first generated token
+                req.tokens_out.append(int(jnp.argmax(logits[0])))
+            self.stats.tokens += 1
+            self.stats.sim_time_us += self.costs.local_write * need
+            if req.first_token_us < 0:
+                req.first_token_us = self.stats.sim_time_us
+            req.status = "active"
+            req.last_active_step = self.step_counter
+            if len(req.tokens_out) >= req.max_new:
+                req.status = "done"
+                self._slots_free.append(req.slot)
+                self._free_pages(req)
+                req.slot = -1
+            return True
+
+    def _resume(self, req: Request) -> bool:
+        if not self._slots_free:
+            return False
+        tr = self.tracer
+        with (tr.span("resume", req.rid) if tr.on else OFF):
+            if self.policy.evict_action == "delete" or not req.pages:
+                # pages were deleted: re-prefill prompt + generated tokens,
+                # EXCLUDING the newest one — the next decode step consumes it
+                full = np.concatenate(
+                    [req.prompt, np.asarray(req.tokens_out[:-1], np.int64)])
+                need = self._pages_for(len(full) + 1)
+                if self.pool.free_count() < need and not self._reserve(need):
+                    return False
+                req.slot = self._slots_free.pop()
+                if not self._alloc_pages(req, need):
+                    raise RuntimeError(
+                        f"resume: failed to allocate {need} pages")
+                self._prefill_one(full, req.slot, self._block_table_row(req))
+                self.stats.recomputes += 1
+                self.stats.sim_time_us += self.costs.cold_read * need
+                req.status = "active"
+                req.last_active_step = self.step_counter
+                return True
+            if not self._restore(req):
+                return False
+            req.slot = self._slots_free.pop()
+            # ring/ssm/cross caches still hold this slot's data only if the
+            # seq kept its batch slot; after pause we must re-own a slot.  For
+            # exact state we spill/restore those too via host blobs keyed by
+            # rid.
+            blob = self._seq_blobs.pop(req.rid, None)
+            if blob is not None:
+                self._write_seq_blob(req.slot, blob)
             req.status = "active"
             req.last_active_step = self.step_counter
             return True
-        if not self._restore(req):
-            return False
-        req.slot = self._slots_free.pop()
-        # ring/ssm/cross caches still hold this slot's data only if the seq
-        # kept its batch slot; after pause we must re-own a slot.  For exact
-        # state we spill/restore those too via host blobs keyed by rid.
-        blob = self._seq_blobs.pop(req.rid, None)
-        if blob is not None:
-            self._write_seq_blob(req.slot, blob)
-        req.status = "active"
-        req.last_active_step = self.step_counter
-        return True
 
     # per-sequence (non-paged) cache spill helpers
     def _read_seq_blob(self, slot: int):
@@ -690,29 +716,32 @@ class ValetServeEngine:
         ``False`` once nothing is waiting, paused, or active — the
         serve_qps benchmark drives this directly, interleaving arrivals
         between iterations; ``run()`` just loops it."""
-        sim_before = self.stats.sim_time_us
-        pending = [r for r in self._requests.values()
-                   if r.status in ("waiting", "paused")]
-        for r in pending:
-            if r.status == "waiting":
-                self._admit(r)
-            else:
-                self._resume(r)
-        # background write-back slice: secure host copies for recently
-        # demoted pages while the foreground decodes
-        self._flush_demoted(self.flush_batch)
-        active = [r for r in self._requests.values() if r.status == "active"]
-        if not active:
-            # True while something is still pending (deadlock guard: the
-            # caller retries, admissions force room next iteration)
-            return any(r.status in ("waiting", "paused")
-                       for r in self._requests.values())
-        self._step_active(active, greedy)
-        # one scheduler iteration = one critical-path latency sample
-        # (admit + resume/fence + decode); the reservoir backs
-        # EngineStats.latency_p50/p99
-        self.stats.lat.record(self.stats.sim_time_us - sim_before)
-        return True
+        tr = self.tracer
+        with (tr.step(self.step_counter) if tr.on else OFF):
+            sim_before = self.stats.sim_time_us
+            pending = [r for r in self._requests.values()
+                       if r.status in ("waiting", "paused")]
+            for r in pending:
+                if r.status == "waiting":
+                    self._admit(r)
+                else:
+                    self._resume(r)
+            # background write-back slice: secure host copies for recently
+            # demoted pages while the foreground decodes
+            self._flush_demoted(self.flush_batch)
+            active = [r for r in self._requests.values()
+                      if r.status == "active"]
+            if not active:
+                # True while something is still pending (deadlock guard: the
+                # caller retries, admissions force room next iteration)
+                return any(r.status in ("waiting", "paused")
+                           for r in self._requests.values())
+            self._step_active(active, greedy)
+            # one scheduler iteration = one critical-path latency sample
+            # (admit + resume/fence + decode); the reservoir backs
+            # EngineStats.latency_p50/p99
+            self.stats.lat.record(self.stats.sim_time_us - sim_before)
+            return True
 
     def run(self, max_steps: int = 10_000, greedy: bool = True):
         """Drive until all requests are done (or max_steps)."""
@@ -726,133 +755,146 @@ class ValetServeEngine:
         return [r for r in self._requests.values()]
 
     def _step_active(self, active: List[Request], greedy: bool):
-        self.step_counter += 1
-        if self._lease is not None:
-            # demand signal: busy engines are reclaimed from last (§3.4)
-            self.coordinator.note_activity(self._lease.cid, len(active))
-        # one device->host transfer for every sequence length this step
-        # (instead of one blocking scalar read per request)
-        lengths = np.asarray(self.caches["lengths"])
-        # grow pages where the next token crosses a page boundary
-        for r in active:
-            pos = int(lengths[r.slot])
-            if pos % self.page == 0 and self._pages_for(pos + 1) > len(r.pages):
-                if self._alloc_page(r) is None:
-                    self._preempt(r)
-        active = [r for r in active if r.status == "active"]
-        if not active:
-            return
+        tr = self.tracer
+        with (tr.span("decode.prepare") if tr.on else OFF):
+            self.step_counter += 1
+            if self._lease is not None:
+                # demand signal: busy engines are reclaimed from last (§3.4)
+                self.coordinator.note_activity(self._lease.cid, len(active))
+            # one device->host transfer for every sequence length this step
+            # (instead of one blocking scalar read per request)
+            lengths = np.asarray(self.caches["lengths"])
+            # grow pages where the next token crosses a page boundary
+            for r in active:
+                pos = int(lengths[r.slot])
+                if (pos % self.page == 0
+                        and self._pages_for(pos + 1) > len(r.pages)):
+                    if self._alloc_page(r) is None:
+                        self._preempt(r)
+            active = [r for r in active if r.status == "active"]
+            if not active:
+                return
 
-        bt = np.full((self.max_batch, self.max_pages), -1, np.int32)
-        app_slot = np.zeros((self.max_batch,), np.int32)
-        app_off = np.zeros((self.max_batch,), np.int32)
-        toks = np.zeros((self.max_batch,), np.int64)
-        act = np.zeros((self.max_batch,), bool)
-        # one batched KV-page table resolution for the whole decode step:
-        # every active request's pages through a single vectorized gather
-        flat_pages = np.concatenate(
-            [np.asarray(r.pages[: self.max_pages], np.int64)
-             for r in active]) if active else np.empty(0, np.int64)
-        flat_slots = self.gpt.local_slots_batch(flat_pages)
-        step_pages = []
-        off = 0
-        for r in active:
-            b = r.slot
-            npg = min(len(r.pages), self.max_pages)
-            bt[b, :npg] = flat_slots[off:off + npg]
-            pos = int(lengths[b])
-            pidx = pos // self.page
-            pg = r.pages[pidx]
-            # pidx can pass max_pages when a sequence outgrows the block
-            # table (nothing caps submit length); resolve those the scalar
-            # way instead of reading past this request's gather window
-            app_slot[b] = flat_slots[off + pidx] if pidx < npg \
-                else self.gpt.local_slot(pg)
-            app_off[b] = pos % self.page
-            toks[b] = (r.tokens_out[-1] if r.tokens_out
-                       else r.prompt[-1])
-            act[b] = True
-            step_pages.append(pg)
-            r.last_active_step = self.step_counter
-            off += npg
-        self.tracker.on_write(step_pages, self.step_counter)
+            bt = np.full((self.max_batch, self.max_pages), -1, np.int32)
+            app_slot = np.zeros((self.max_batch,), np.int32)
+            app_off = np.zeros((self.max_batch,), np.int32)
+            toks = np.zeros((self.max_batch,), np.int64)
+            act = np.zeros((self.max_batch,), bool)
+            # one batched KV-page table resolution for the whole decode
+            # step: every active request's pages through a single
+            # vectorized gather
+            flat_pages = np.concatenate(
+                [np.asarray(r.pages[: self.max_pages], np.int64)
+                 for r in active])
+            flat_slots = self.gpt.local_slots_batch(flat_pages)
+            step_pages = []
+            off = 0
+            for r in active:
+                b = r.slot
+                npg = min(len(r.pages), self.max_pages)
+                bt[b, :npg] = flat_slots[off:off + npg]
+                pos = int(lengths[b])
+                pidx = pos // self.page
+                pg = r.pages[pidx]
+                # pidx can pass max_pages when a sequence outgrows the block
+                # table (nothing caps submit length); resolve those the
+                # scalar way instead of reading past this request's gather
+                # window
+                app_slot[b] = flat_slots[off + pidx] if pidx < npg \
+                    else self.gpt.local_slot(pg)
+                app_off[b] = pos % self.page
+                toks[b] = (r.tokens_out[-1] if r.tokens_out
+                           else r.prompt[-1])
+                act[b] = True
+                step_pages.append(pg)
+                r.last_active_step = self.step_counter
+                off += npg
+            self.tracker.on_write(step_pages, self.step_counter)
+            inputs = (jnp.asarray(toks), jnp.asarray(bt),
+                      jnp.asarray(app_slot), jnp.asarray(app_off),
+                      jnp.asarray(act))
 
-        logits, self.caches = self._decode_jit(
-            self.params, self.caches, jnp.asarray(toks), jnp.asarray(bt),
-            jnp.asarray(app_slot), jnp.asarray(app_off), jnp.asarray(act))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        self.stats.steps += 1
-        self.stats.sim_time_us += self.step_cost_us \
-            + self.costs.local_write * len(active)
-        for r in active:
-            r.tokens_out.append(int(nxt[r.slot]))
-            self.stats.tokens += 1
-            if len(r.tokens_out) >= r.max_new:
-                r.status = "done"
-                self._slots_free.append(r.slot)
-                self._free_pages(r)
-                r.slot = -1
+        with (tr.span("decode.dispatch", n=len(active)) if tr.on else OFF):
+            logits, self.caches = self._decode_jit(self.params, self.caches,
+                                                   *inputs)
+        # the step's wait on the device: the sampled tokens reach the host
+        with (tr.span("decode.readback") if tr.on else OFF):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with (tr.span("decode.emit") if tr.on else OFF):
+            self.stats.steps += 1
+            self.stats.sim_time_us += self.step_cost_us \
+                + self.costs.local_write * len(active)
+            for r in active:
+                r.tokens_out.append(int(nxt[r.slot]))
+                self.stats.tokens += 1
+                if len(r.tokens_out) >= r.max_new:
+                    r.status = "done"
+                    self._slots_free.append(r.slot)
+                    self._free_pages(r)
+                    r.slot = -1
 
     def _preempt(self, req: Request) -> int:
         """Pause a sequence: demote (zero-restore), spill (legacy valet /
         os-swap) or delete (infiniswap) its pool pages + save its per-slot
         (ring/ssm/cross) caches."""
-        n = len(req.pages)
-        self.stats.pauses += 1
-        if req.slot >= 0:
-            self._seq_blobs[req.rid] = self._read_seq_blob(req.slot)
-            self._slots_free.append(req.slot)
-            req.slot = -1
-        if self.policy.evict_action == "delete":
-            self._free_pages(req)
-            req.status = "paused"
-            req.n_recomputes += 1
-            self.stats.deleted_pages += n
-            self._seq_blobs.pop(req.rid, None)
-            return n
-        live = np.empty(0, np.int64)
-        if req.pages:
-            parr = np.asarray(req.pages, np.int64)
-            lslots = self.gpt.local_slots_batch(parr)
-            mask = lslots >= 0
-            live = parr[mask]
-            live_slots = lslots[mask]
-        if live.size and self._zero:
-            # zero-restore demote: a pure metadata move.  The slots return
-            # to the free list but the KV bytes stay put, registered with
-            # the device tier under the pool's current generation; the
-            # background flush secures host copies before any reuse.  No
-            # device traffic, no critical-path cost here.
-            m = int(live.size)
-            self.device.demote(live.tolist(), live_slots.tolist(),
-                               self.pool.gen[live_slots].tolist())
-            self.pool.release_batch(live_slots.tolist())
-            self.gpt.unmap_local_batch(live)
-            self.gpt.map_remote_batch(live, [int(Tier.DEVICE)] * m,
-                                      [-1] * m, live_slots.tolist(), None)
-            self._flush_q.extend(live.tolist())
-            self.stats.demoted_pages += m
-            self.stats.spilled_pages += m
-        elif live.size:
-            # legacy eager spill: every page to the host tier now, then
-            # grouped release / unmap / remote-map
-            self._pages_to_host(live.tolist(), live_slots.tolist())
-            self.pool.release_batch(live_slots.tolist())
-            self.gpt.unmap_local_batch(live)
-            m = int(live.size)
-            self.gpt.map_remote_batch(live, [int(Tier.HOST)] * m,
-                                      [-1] * m, [-1] * m, None)
-            self.stats.spilled_pages += m
-            cost = self.costs.host_write * m
-            if self.policy.lazy_send:
-                if self.async_mode:
-                    # charge the daemon clock: the spill overlaps decode,
-                    # but a restore of these pages must fence on it
-                    self.daemon.charge(cost, self.stats.sim_time_us)
-                    self.stats.daemon_us += cost
+        tr = self.tracer
+        with (tr.span("preempt", req.rid) if tr.on else OFF):
+            n = len(req.pages)
+            self.stats.pauses += 1
+            if req.slot >= 0:
+                self._seq_blobs[req.rid] = self._read_seq_blob(req.slot)
+                self._slots_free.append(req.slot)
+                req.slot = -1
+            if self.policy.evict_action == "delete":
+                self._free_pages(req)
+                req.status = "paused"
+                req.n_recomputes += 1
+                self.stats.deleted_pages += n
+                self._seq_blobs.pop(req.rid, None)
+                return n
+            live = np.empty(0, np.int64)
+            if req.pages:
+                parr = np.asarray(req.pages, np.int64)
+                lslots = self.gpt.local_slots_batch(parr)
+                mask = lslots >= 0
+                live = parr[mask]
+                live_slots = lslots[mask]
+            if live.size and self._zero:
+                # zero-restore demote: a pure metadata move.  The slots return
+                # to the free list but the KV bytes stay put, registered with
+                # the device tier under the pool's current generation; the
+                # background flush secures host copies before any reuse.  No
+                # device traffic, no critical-path cost here.
+                m = int(live.size)
+                self.device.demote(live.tolist(), live_slots.tolist(),
+                                   self.pool.gen[live_slots].tolist())
+                self.pool.release_batch(live_slots.tolist())
+                self.gpt.unmap_local_batch(live)
+                self.gpt.map_remote_batch(live, [int(Tier.DEVICE)] * m,
+                                          [-1] * m, live_slots.tolist(), None)
+                self._flush_q.extend(live.tolist())
+                self.stats.demoted_pages += m
+                self.stats.spilled_pages += m
+            elif live.size:
+                # legacy eager spill: every page to the host tier now, then
+                # grouped release / unmap / remote-map
+                self._pages_to_host(live.tolist(), live_slots.tolist())
+                self.pool.release_batch(live_slots.tolist())
+                self.gpt.unmap_local_batch(live)
+                m = int(live.size)
+                self.gpt.map_remote_batch(live, [int(Tier.HOST)] * m,
+                                          [-1] * m, [-1] * m, None)
+                self.stats.spilled_pages += m
+                cost = self.costs.host_write * m
+                if self.policy.lazy_send:
+                    if self.async_mode:
+                        # charge the daemon clock: the spill overlaps decode,
+                        # but a restore of these pages must fence on it
+                        self.daemon.charge(cost, self.stats.sim_time_us)
+                        self.stats.daemon_us += cost
+                    else:
+                        self.stats.bg_time_us += cost
                 else:
-                    self.stats.bg_time_us += cost
-            else:
-                self.stats.sim_time_us += cost
-        req.status = "paused"
-        return n
+                    self.stats.sim_time_us += cost
+            req.status = "paused"
+            return n
