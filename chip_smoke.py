@@ -17,7 +17,9 @@ One process drives the chip.  Phases run in order and any failure is fatal:
    pages demoted, flushed to the pinned-host tier and restored; the greedy
    tokens must equal the roomy run's.
 5. reference: the engine's first-token logits for one prompt against
-   ``models.transformer.prefill_logits`` on the same weights in float32.
+   ``models.transformer.prefill_logits`` on the same weights in float32,
+   computed before serving from the stacked weights, which are then split
+   per layer once (``decode.take_layers``) for both engines.
 
 ``--chips 4`` runs one phase instead: a train step at phi3's widths, depth
 cut to 2 layers, on a (data 2, model 2) mesh against the same step on one
@@ -66,10 +68,10 @@ class Geometry:
     batch: int = 8
     page: int = 16
     # 256 16-token slots per paged layer hold every request with room to
-    # spare.  The v5e compiler's memory analysis puts the decode step at
-    # 13.55 GiB (the KV pools count twice: the step does not donate them),
-    # 2.2 GiB under the chip's 15.75; tests/test_chip_compile.py keeps it
-    # at least 1 GiB under.
+    # spare.  The v5e compiler's memory analysis puts the decode step from
+    # the per-layer weights at 10.21 GiB (the KV pools count twice: the
+    # step does not donate them), 5.5 GiB under the chip's 15.75;
+    # tests/test_chip_compile.py keeps it at least 1 GiB under.
     roomy_slots: int = 256
 
     @property
@@ -222,13 +224,25 @@ def serve_check(params, cfg, ctx, geo: Geometry, prompts):
     return eng, roomy_tokens, tokens
 
 
-def reference_check(eng, params, cfg, prompt) -> float:
-    """Relative L2 error of the engine's first-token logits for ``prompt``
-    against ``prefill_logits`` computed in float32 on the same weights."""
+def reference_logits(params, cfg, prompt):
+    """``prefill_logits`` for ``prompt`` in float32 on the stacked weights:
+    the first-token logits the engine's are held to, on the host."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.models import transformer as T
+
+    ref_ctx = T.ParallelCtx(remat=False, compute_dtype=jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda p, t: T.prefill_logits(p, t, cfg, ref_ctx))(
+            params, jnp.asarray(prompt)[None])
+    return np.asarray(ref[0], np.float32)[: cfg.vocab]
+
+
+def reference_check(eng, ref, cfg, prompt) -> float:
+    """Relative L2 error of the engine's first-token logits for ``prompt``
+    against ``ref`` (``reference_logits``)."""
+    import numpy as np
 
     # the engine's own prefill program (already compiled for this prompt
     # length) into slots 0.. of its now idle pool
@@ -236,12 +250,6 @@ def reference_check(eng, params, cfg, prompt) -> float:
     n = -(-(len(prompt) + 1) // eng.page)
     bt[:n] = np.arange(n)
     got = np.asarray(eng._prefill_one(prompt, 0, bt)[0], np.float32)
-
-    ref_ctx = T.ParallelCtx(remat=False, compute_dtype=jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        ref = jax.jit(lambda p, t: T.prefill_logits(p, t, cfg, ref_ctx))(
-            params, jnp.asarray(prompt)[None])
-    ref = np.asarray(ref[0], np.float32)[: cfg.vocab]
     got = got[: cfg.vocab]
     check(bool(np.isfinite(got).all()), "engine logits not finite")
     rel = float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
@@ -256,6 +264,7 @@ def reference_check(eng, params, cfg, prompt) -> float:
 
 def serve_phases(clog: CompileLog) -> None:
     from repro.launch.serve import build_model
+    from repro.models import decode as D
 
     t0 = time.monotonic()
     cfg, ctx, params = build_model(ARCH, local=False)
@@ -277,14 +286,24 @@ def serve_phases(clog: CompileLog) -> None:
           f"(pages needed {geo.requests * geo.pages_per_request})",
           flush=True)
     prompts = make_prompts(geo, cfg.vocab)
+    # the float32 reference reads the stacked weights, so it comes first;
+    # then they are split once, in place, and both engines serve from the
+    # same per-layer arrays: the weights are never on the device twice
+    ref = reference_logits(params, cfg, prompts[0])
+    clog.report("reference")
+    print(f"  [reference] peak_bytes_in_use={peak_bytes()}", flush=True)
+    t0 = time.monotonic()
+    D.take_layers(params)
+    jax.block_until_ready(params)
+    print(f"  [split] wall_s={time.monotonic() - t0:.2f} "
+          f"peak_bytes_in_use={peak_bytes()}", flush=True)
+
     eng, _, _ = serve_check(params, cfg, ctx, geo, prompts)
     clog.report("serve")
     print(f"  [serve] peak_bytes_in_use={peak_bytes()}", flush=True)
 
-    reference_check(eng, params, cfg, prompts[0])
+    reference_check(eng, ref, cfg, prompts[0])
     free_engine(eng)
-    clog.report("reference")
-    print(f"  [reference] peak_bytes_in_use={peak_bytes()}", flush=True)
 
 
 # ----------------------------------------------------------------- train

@@ -74,6 +74,9 @@ def main():
         max_seq=args.prompt_len + args.max_new + args.page,
         page=args.page, pool_slots=args.pool_slots,
         policy=POLICIES[args.policy])
+    # the engine splits the weights per layer at its first program call;
+    # dropping the stacked ones here keeps one copy on the device
+    del params
     rng = np.random.default_rng(0)
     for _ in range(args.requests):
         eng.submit(rng.integers(2, cfg.vocab, size=args.prompt_len),

@@ -74,7 +74,50 @@ def layer_infos(cfg: ArchConfig) -> List[LayerInfo]:
 
 
 def layer_params(params, info: LayerInfo):
-    return jax.tree.map(lambda a: a[info.idx], params["segments"][info.seg])
+    """The weights of one layer: an element of a split segment (a list, see
+    ``take_layers``), else a slice of each ``[count, ...]`` stacked leaf."""
+    seg = params["segments"][info.seg]
+    if isinstance(seg, list):
+        return seg[info.idx]
+    return jax.tree.map(lambda a: a[info.idx], seg)
+
+
+def take_layers(params):
+    """Split ``params`` in place: each stacked segment
+    ``params["segments"][i]`` becomes the list of its layers' trees, holding
+    the same values.  A segment already split is left as it is.  Returns
+    ``params``.
+
+    A program that takes the split form reads each layer's weights as its
+    own arguments; one that takes the stacked form slices them out inside
+    the program, which on TPU copies every weight of every layer on every
+    call.
+
+    Each stacked leaf is dropped as soon as its layers are made, so a caller
+    that holds the only reference to the stacked leaves never has the
+    weights on the device twice: the split needs room for one more copy of
+    the largest leaf, not of every leaf.  To keep a tree whole, split a copy
+    of its containers: ``take_layers(jax.tree.map(lambda a: a, params))``."""
+    segs = params["segments"]
+    # by index: a loop variable (or ``enumerate``'s cached tuple) would keep
+    # the stacked segment, and so every leaf of it, alive to the loop's end
+    for si in range(len(segs)):
+        if isinstance(segs[si], list):
+            continue
+        leaves, treedef = jax.tree.flatten(segs[si])
+        segs[si] = None
+        cols = []
+        for j in range(len(leaves)):
+            a, leaves[j] = leaves[j], None
+            cols.append(_unstack(a))
+            del a
+        segs[si] = [treedef.unflatten(layer) for layer in zip(*cols)]
+    return params
+
+
+@jax.jit
+def _unstack(a):
+    return [a[i] for i in range(a.shape[0])]
 
 
 # --------------------------------------------------------------------------

@@ -127,7 +127,10 @@ class ValetServeEngine:
                 "weight=... (or OrchestrationConfig(weight=...) with "
                 "ValetServeEngine.from_config())", DeprecationWarning,
                 stacklevel=2)
-        self.params = params
+        # the caller's weights, in containers of the engine's own; ``params``
+        # splits each stacked segment at its first read (``decode.take_layers``)
+        self._unsplit = jax.tree.map(lambda a: a, params)
+        self._params = None
         self.cfg = cfg
         self.ctx = ctx
         self.page = page
@@ -210,6 +213,29 @@ class ValetServeEngine:
         self._prefill_lengths: set = set()
         # the engine's spans, off until ``tracer.start()`` (serve/tracing.py)
         self.tracer = Tracer()
+
+    @property
+    def params(self):
+        """The weights as the engine's programs take them, each layer's as
+        its own arrays (``decode.take_layers``), so the decode step copies
+        no weight out of a ``[count, ...]`` stack.
+
+        Weights handed over split are served as they are, shared with the
+        caller.  Stacked ones are split at the first read, which the first
+        program call makes, and each stacked leaf is dropped as soon as it
+        is split.  The weights are then on the device once only if the
+        caller has dropped its stacked ``params`` by then; a caller that
+        keeps them holds a second copy.  To keep the weights while serving,
+        or to serve several engines from one set, split them first:
+        ``decode.take_layers(params)``."""
+        if self._unsplit is not None:
+            given, self._unsplit = self._unsplit, None
+            self._params = D.take_layers(given)
+        return self._params
+
+    @params.setter
+    def params(self, value):
+        self._unsplit, self._params = None, value
 
     @property
     def host_store(self) -> Dict[int, dict]:

@@ -13,6 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 import chip_smoke  # noqa: E402
 from repro.configs import get_arch, reduced  # noqa: E402
+from repro.models import decode as D  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 
 GEO = chip_smoke.Geometry(requests=6, prompt=16, new=12, batch=3, page=4,
@@ -26,8 +27,14 @@ def test_serve_check_reduced_phi3(dtype):
                         compute_dtype=dtype)
     params = T.init_params(jax.random.PRNGKey(0), cfg, dtype)
     prompts = chip_smoke.make_prompts(GEO, cfg.vocab)
+    # as serve_phases: the reference on the stacked weights, then one split
+    # that both engines serve from
+    ref = chip_smoke.reference_logits(params, cfg, prompts[0])
+    D.take_layers(params)
     eng, roomy, pressured = chip_smoke.serve_check(params, cfg, ctx, GEO,
                                                    prompts)
+    assert all(a is b for a, b in zip(jax.tree.leaves(eng.params),
+                                      jax.tree.leaves(params)))
     assert pressured == roomy
     assert all(len(t) == GEO.new for t in pressured)
     s = eng.stats
@@ -35,7 +42,7 @@ def test_serve_check_reduced_phi3(dtype):
     assert s.pauses > 0 and s.demoted_pages > 0 and s.flushed_pages > 0
     assert s.repointed_pages + s.streamed_pages > 0
     assert eng.caches["layers"][0]["pool"].k.dtype == dtype
-    rel = chip_smoke.reference_check(eng, params, cfg, prompts[0])
+    rel = chip_smoke.reference_check(eng, ref, cfg, prompts[0])
     assert rel < (1e-5 if dtype == jnp.float32 else
                   chip_smoke.LOGIT_REL_L2_TOL)
 

@@ -1,5 +1,7 @@
 """Serving-engine behaviour: exactness under memory pressure for every
 policy, plus the cost separation the paper reports."""
+import weakref
+
 import numpy as np
 import pytest
 
@@ -7,6 +9,7 @@ import jax
 
 from repro.configs import ARCHS, reduced
 from repro.core.policies import POLICIES
+from repro.models import decode as D
 from repro.models import transformer as T
 from repro.serve import ValetServeEngine
 
@@ -137,3 +140,117 @@ def test_engine_hybrid_arch_with_rings():
     out, _, reqs = run_engine(params, cfg, prompts, "valet", slots=8)
     assert all(r.status == "done" for r in reqs)
     assert out == ref
+
+
+def _weight_bytes(tree) -> int:
+    return sum(a.nbytes for a in jax.tree.leaves(tree))
+
+
+def _new_arrays(before, engines):
+    """The live arrays made since ``before``, the engines' caches left
+    out."""
+    old = {id(a) for a in before}
+    old |= {id(a) for e in engines for a in jax.tree.leaves(e.caches)}
+    return [a for a in jax.live_arrays() if id(a) not in old]
+
+
+def test_engine_holds_the_weights_once():
+    """The engine splits the weights into per-layer arrays at its first
+    program call.  Once the caller has dropped its ``params``, the live
+    arrays hold one copy of the weights, none of them stacked."""
+    cfg = reduced(ARCHS["granite-3-8b"])
+    before = jax.live_arrays()
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    n_bytes = _weight_bytes(params)
+    stacked_shapes = {a.shape for a in jax.tree.leaves(params["segments"])}
+    eng = ValetServeEngine(params, cfg, CTX, max_batch=2, max_seq=32,
+                           page=4, pool_slots=16)
+    del params
+    eng.submit(np.arange(2, 10), max_new=4)
+    eng.run(max_steps=50)
+    own = jax.tree.leaves(eng.params)
+    assert _weight_bytes(own) == n_bytes
+    new = _new_arrays(before, [eng])
+    weights = [a for a in new if any(a is b for b in own)]
+    assert _weight_bytes(weights) == n_bytes
+    assert not any(a.shape in stacked_shapes for a in weights)
+    others = [a for a in new if not any(a is b for b in own)]
+    assert _weight_bytes(others) < n_bytes // 10, [
+        a.shape for a in others]
+
+
+def test_engine_drops_each_stack_as_it_splits(monkeypatch):
+    """Handed stacked weights the caller then drops, the engine's split
+    frees each stacked leaf before it splits the next: the device holds
+    the weights plus one leaf at most, not the weights twice."""
+    cfg = reduced(ARCHS["granite-3-8b"])
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    refs = [weakref.ref(a) for a in jax.tree.leaves(params["segments"])]
+    eng = ValetServeEngine(params, cfg, CTX, max_batch=2, max_seq=32,
+                           page=4, pool_slots=16)
+    del params
+    alive = []
+    unstack = D._unstack
+
+    def counting(a):
+        alive.append(sum(r() is not None for r in refs))
+        return unstack(a)
+
+    monkeypatch.setattr(D, "_unstack", counting)
+    eng.params
+    assert alive == list(range(len(refs), 0, -1))
+    assert all(r() is None for r in refs)
+
+
+def test_engine_leaves_the_callers_weights_whole():
+    """While the caller keeps its stacked ``params``, the engine's per-layer
+    arrays are new ones with the same values, and the caller's are not
+    deleted: the device holds the layers twice.  The leaves outside the
+    layers (embedding, final norm) are shared."""
+    cfg = reduced(ARCHS["granite-3-8b"])
+    before = jax.live_arrays()
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    eng = ValetServeEngine(params, cfg, CTX, max_batch=2, max_seq=32,
+                           page=4, pool_slots=16)
+    split = eng.params
+    theirs = jax.tree.leaves(params)
+    assert not any(a.is_deleted() for a in theirs)
+    assert not any(a is b for a in jax.tree.leaves(split["segments"])
+                   for b in theirs)
+    assert split["embed"] is params["embed"]
+    assert isinstance(params["segments"][0], dict)
+    for seg, layers in zip(params["segments"], split["segments"]):
+        for i, layer in enumerate(layers):
+            for a, b in zip(jax.tree.leaves(seg), jax.tree.leaves(layer)):
+                np.testing.assert_array_equal(np.asarray(b),
+                                              np.asarray(a[i]))
+    live = _weight_bytes(_new_arrays(before, [eng]))
+    assert live >= _weight_bytes(params) + _weight_bytes(split["segments"])
+
+
+def test_engines_share_weights_handed_over_split():
+    """Weights the caller splits first (``decode.take_layers``) are served
+    as they are: the caller and two engines that have served from them
+    hold one copy of the weights between them."""
+    cfg = reduced(ARCHS["granite-3-8b"])
+    before = jax.live_arrays()
+    params = D.take_layers(T.init_params(jax.random.PRNGKey(0), cfg))
+    theirs = jax.tree.leaves(params)
+    n_bytes = _weight_bytes(theirs)
+    engines, outs = [], []
+    for _ in range(2):
+        eng = ValetServeEngine(params, cfg, CTX, max_batch=2, max_seq=32,
+                               page=4, pool_slots=16)
+        eng.submit(np.arange(2, 10), max_new=4)
+        (req,) = eng.run(max_steps=50)
+        outs.append(req.tokens_out)
+        engines.append(eng)
+        own = jax.tree.leaves(eng.params)
+        assert len(own) == len(theirs)
+        assert all(a is b for a, b in zip(own, theirs))
+    assert outs[0] == outs[1] and len(outs[0]) == 4
+    new = _new_arrays(before, engines)
+    weights = [a for a in new if any(a is b for b in theirs)]
+    assert _weight_bytes(weights) == n_bytes
+    assert _weight_bytes(new) - n_bytes < n_bytes // 10, [
+        a.shape for a in new if not any(a is b for b in theirs)]
